@@ -215,8 +215,7 @@ class CheckContext:
         try:
             topo = self.program.schedule.topologies[0]
             fl = consensus.stacked_flat_comm(
-                topo, interpret=True, exchange=self.program.exchange,
-                program=self.program)
+                topo, exchange=self.program.exchange, program=self.program)
             return jax.eval_shape(
                 lambda p: consensus.initial_wire_state(fl, p), self.params)
         except Exception:

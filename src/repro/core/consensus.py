@@ -477,7 +477,7 @@ class FlatComm:
     lead: int                     # leading replica axes excluded from packing
     batched: bool                 # True: stacked simulation (dense Pi vmap)
     gather: Callable              # (bufs, seed) -> (nbrs, weights, scales, selfs)
-    interpret: bool = True        # interpret=True for CPU; False on TPU
+    interpret: Optional[bool] = None   # None: resolve_interpret (backend)
     exchange: str = "f32"         # wire precision: f32 | bf16 | int8 | fp8
     n_agents: int = 1
     # split phase stages (see class docstring); None on comms predating them
@@ -554,7 +554,7 @@ def _check_exchange(exchange: str) -> str:
     return exchange
 
 
-def _wire_payload(buf, seed, exchange: str, interpret: bool):
+def _wire_payload(buf, seed, exchange: str, interpret: Optional[bool]):
     """Cast/quantize one packed bucket for the wire -> (payload, scales).
 
     ``bf16`` casts the whole stencil *including* the self tile: without
@@ -570,8 +570,8 @@ def _wire_payload(buf, seed, exchange: str, interpret: bool):
     return sr_quantize_2d(buf, seed, exchange=exchange, interpret=interpret)
 
 
-def _quantize_wire_stacked(bufs, seed, n: int, exchange: str, interpret: bool,
-                           payload: int = 0):
+def _quantize_wire_stacked(bufs, seed, n: int, exchange: str,
+                           interpret: Optional[bool], payload: int = 0):
     """Quantize agent-stacked ``(A, rows, 128)`` buckets for the wire.
 
     Returns the wire state: one ``(payload, (A, rows, 1) f32 scales)`` pair
@@ -675,7 +675,7 @@ def _is_compressed_entry(entry) -> bool:
 
 
 def _compress_wire_stacked(bufs, seed, n: int, program: MixingProgram,
-                           interpret: bool, qwarm):
+                           interpret: Optional[bool], qwarm):
     """Compress agent-stacked ``(A, rows, 128)`` buckets for the wire.
 
     The compressed analog of :func:`_quantize_wire_stacked`: per-agent
@@ -1189,7 +1189,7 @@ def _self_separated_weights(pi: np.ndarray) -> np.ndarray:
                            pi * (1.0 - np.eye(n))], axis=1)
 
 
-def stacked_flat_comm(topology: Topology, *, interpret: bool = True,
+def stacked_flat_comm(topology: Topology, *, interpret: Optional[bool] = None,
                       exchange: str = "f32",
                       program: Optional[MixingProgram] = None) -> FlatComm:
     """FlatComm for agent-stacked pytrees (dense ``Pi``, any topology).
@@ -1326,7 +1326,7 @@ def stacked_flat_comm(topology: Topology, *, interpret: bool = True,
 
 
 def sharded_flat_comm(factors: Sequence[Tuple[str, Topology]], *,
-                      lead: int = 1, interpret: bool = True,
+                      lead: int = 1, interpret: Optional[bool] = None,
                       exchange: str = "f32",
                       program: Optional[MixingProgram] = None) -> FlatComm:
     """FlatComm for use inside ``shard_map``; circulant topologies only.
@@ -1864,11 +1864,19 @@ def initial_qwarm_state(fl: FlatComm, params: PyTree) -> tuple:
 
 
 def mix_stacked(pi: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
-    """``(Pi x)_j = sum_l pi_{jl} x_l`` for ``x`` of shape (N, ...)."""
+    """``(Pi x)_j = sum_l pi_{jl} x_l`` for ``x`` of shape (N, ...).
+
+    An f32 sum of N broadcast products, not a dot: a dot flattens every
+    leaf into one ``(N, size)`` operand, and on a TPU that relayout of a
+    large leaf is slower to compile than the rest of the train step.
+    """
     pi = jnp.asarray(pi, dtype=jnp.float32)
-    flat = x.reshape(x.shape[0], -1)
-    mixed = jnp.einsum("jl,ld->jd", pi, flat.astype(jnp.float32))
-    return mixed.astype(x.dtype).reshape(x.shape)
+    xf = x.astype(jnp.float32)
+    col = (x.shape[0],) + (1,) * (x.ndim - 1)
+    mixed = pi[:, 0].reshape(col) * xf[0]
+    for l in range(1, x.shape[0]):
+        mixed = mixed + pi[:, l].reshape(col) * xf[l]
+    return mixed.astype(x.dtype)
 
 
 def mix_pytree_stacked(pi: jnp.ndarray, tree: PyTree) -> PyTree:

@@ -67,6 +67,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 from repro.core import consensus
 from repro.core.optim import (
@@ -510,7 +511,7 @@ def _sub_jaxprs(params: dict):
     for v in params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for x in vals:
-            if isinstance(x, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+            if isinstance(x, (Jaxpr, ClosedJaxpr)):
                 yield x
 
 
@@ -533,7 +534,7 @@ def _taint_walk(jaxpr, in_taints, hits, prims, path=()):
     env = {}
 
     def read(v):
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             return frozenset()
         return env.get(v, frozenset())
 
@@ -549,7 +550,7 @@ def _taint_walk(jaxpr, in_taints, hits, prims, path=()):
         if subs:
             acc = None
             for si, sub in enumerate(subs):
-                j = sub.jaxpr if isinstance(sub, jax.core.ClosedJaxpr) else sub
+                j = sub.jaxpr if isinstance(sub, ClosedJaxpr) else sub
                 n = len(j.invars)
                 if n == len(ins):
                     sub_in = list(ins)

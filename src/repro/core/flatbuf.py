@@ -14,10 +14,15 @@ view instead:
   128-wide rows — so the packed buffer is a ``(*lead, rows, 128)`` array
   whose layout is described entirely by compile-time metadata
   (:class:`FlatSpec`);
-* ``pack`` is a cast + reshape + **one** concatenate + **one** tail pad per
-  bucket (reshape-only when the bucket is a single 128-aligned leaf);
-  ``unpack`` is a static slice + reshape per leaf — no gathers, no scatter,
-  no host work.
+* ``pack`` is a cast + reshape per leaf, **one** concatenate along the
+  rows axis and **one** tail pad per bucket (reshape-only when the bucket
+  is a single 128-aligned leaf); ``unpack`` is a static slice + reshape per
+  leaf — no gathers, no scatter, no host work.  Both move whole rows:
+  a leaf that starts on a row boundary and fills whole rows reshapes
+  straight to ``(*lead, rows_i, 128)``, and only leaves that straddle a row
+  boundary go through a flat vector (:func:`_row_groups`).  On a TPU the
+  relayout of a large leaf into one flat vector is slow to compile and to
+  run; into 128-lane rows it is a plain tiled copy.
 
 ``lead`` counts leading *replica* axes excluded from flattening: the stacked
 simulation packs ``(A, ...)`` leaves with ``lead=1`` into ``(A, rows, 128)``
@@ -179,34 +184,58 @@ def make_flat_spec(tree: PyTree, lead: int = 0) -> FlatSpec:
     return spec
 
 
+def _row_groups(bucket: BucketSpec):
+    """Split a bucket's slots into row-aligned groups ``(row0, rows, slots)``.
+
+    Each group starts on a row boundary and ends on one (or at the bucket
+    tail).  A leaf that starts on a row boundary and fills whole rows is a
+    group of its own; leaves that straddle a row boundary share one group.
+    The slot offsets stay contiguous — grouping only changes how pack and
+    unpack express the same layout.
+    """
+    groups, cur, start = [], [], 0
+    for slot in bucket.slots:
+        cur.append(slot)
+        end = slot.offset + slot.size
+        if end % LANE == 0:
+            groups.append((start // LANE, (end - start) // LANE, tuple(cur)))
+            cur, start = [], end
+    if cur:
+        groups.append((start // LANE, bucket.rows - start // LANE, tuple(cur)))
+    return groups
+
+
 def pack(tree: PyTree, spec: FlatSpec) -> List[jnp.ndarray]:
     """Pack ``tree`` into one ``(*lead, rows, 128)`` buffer per dtype bucket.
 
     Leaves are cast to their bucket dtype (grads/momenta packed against a
     parameter spec inherit the unfused ``g.astype(param.dtype)`` semantics).
-    Each bucket is ONE concatenate of the flattened leaves plus ONE tail pad
-    up to the row boundary; a single 128-aligned leaf is a pure reshape.
+    Each bucket is ONE concatenate of row blocks plus ONE tail pad up to the
+    row boundary; a single 128-aligned leaf is a pure reshape.
     """
     leaves, treedef = jax.tree.flatten(tree)
     if treedef != spec.treedef:
         raise ValueError(f"tree structure {treedef} != spec structure {spec.treedef}")
+    for slot in (sl for b in spec.buckets for sl in b.slots):
+        x = leaves[slot.index]
+        if tuple(x.shape[spec.lead:]) != slot.shape:
+            raise ValueError(
+                f"leaf {slot.index}: shape {x.shape} != spec {slot.shape} "
+                f"(lead={spec.lead})")
+    lead_shape = tuple(leaves[0].shape[:spec.lead])
     out = []
     for bucket in spec.buckets:
-        pieces = []
-        lead_shape = None
-        for slot in bucket.slots:
-            x = leaves[slot.index]
-            if tuple(x.shape[spec.lead:]) != slot.shape:
-                raise ValueError(
-                    f"leaf {slot.index}: shape {x.shape} != spec {slot.shape} "
-                    f"(lead={spec.lead})")
-            lead_shape = tuple(x.shape[:spec.lead])
-            pieces.append(x.astype(bucket.dtype).reshape(lead_shape + (slot.size,)))
-        flat = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces, axis=-1)
-        padding = bucket.n_padded - bucket.n_real
-        if padding:
-            flat = jnp.pad(flat, [(0, 0)] * spec.lead + [(0, padding)])
-        out.append(flat.reshape(lead_shape + (bucket.rows, LANE)))
+        blocks = []
+        for _, rows, slots in _row_groups(bucket):
+            flat = [leaves[sl.index].astype(bucket.dtype).reshape(
+                lead_shape + (sl.size,)) for sl in slots]
+            flat = flat[0] if len(flat) == 1 else jnp.concatenate(flat, axis=-1)
+            padding = rows * LANE - flat.shape[-1]      # bucket tail only
+            if padding:
+                flat = jnp.pad(flat, [(0, 0)] * spec.lead + [(0, padding)])
+            blocks.append(flat.reshape(lead_shape + (rows, LANE)))
+        out.append(blocks[0] if len(blocks) == 1
+                   else jnp.concatenate(blocks, axis=-2))
     return out
 
 
@@ -217,8 +246,11 @@ def unpack(bufs: Sequence[jnp.ndarray], spec: FlatSpec) -> PyTree:
     leaves: List[Any] = [None] * spec.n_leaves
     for bucket, buf in zip(spec.buckets, bufs):
         lead_shape = tuple(buf.shape[:-2])
-        flat = buf.reshape(lead_shape + (bucket.rows * LANE,))
-        for slot in bucket.slots:
-            piece = flat[..., slot.offset:slot.offset + slot.size]
-            leaves[slot.index] = piece.reshape(lead_shape + slot.shape)
+        for row0, rows, slots in _row_groups(bucket):
+            block = buf[..., row0:row0 + rows, :]
+            flat = block.reshape(lead_shape + (rows * LANE,))
+            for slot in slots:
+                lo = slot.offset - row0 * LANE
+                leaves[slot.index] = flat[..., lo:lo + slot.size].reshape(
+                    lead_shape + slot.shape)
     return jax.tree.unflatten(spec.treedef, leaves)

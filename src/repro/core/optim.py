@@ -62,7 +62,7 @@ def identity_comm_ops() -> CommOps:
     return CommOps(mix=ident, mean=ident, n_agents=1, lambda2=0.0, lambdan=1.0)
 
 
-def stacked_comm_ops(topology, *, interpret: bool = True,
+def stacked_comm_ops(topology, *, interpret: Optional[bool] = None,
                      exchange: str = "f32",
                      program: Optional[consensus.MixingProgram] = None) -> CommOps:
     """CommOps for agent-stacked pytrees (leading axis = agent).

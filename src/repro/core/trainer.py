@@ -70,8 +70,9 @@ class CollaborativeTrainer:
     flat-buffer update here: the stacked ``CommOps`` carries a ``FlatComm``
     (dense ``Pi`` on packed buffers), so each step issues exactly one
     ``pallas_call`` per parameter dtype bucket instead of one mix + axpy
-    per pytree leaf.  ``interpret`` selects Pallas interpret mode (True on
-    CPU, False on TPU).
+    per pytree leaf.  ``interpret`` selects Pallas interpret mode; the
+    default ``None`` follows the backend (compiled kernels on a TPU, see
+    :func:`repro.kernels.resolve_interpret`).
 
     ``exchange`` simulates the neighbor-exchange wire precision of the
     fused path (``"f32"`` native, ``"bf16"``, or ``"int8"``/``"fp8"``
@@ -123,7 +124,7 @@ class CollaborativeTrainer:
         *,
         stack: bool = True,
         donate: bool = True,
-        interpret: bool = True,
+        interpret: Optional[bool] = None,
         exchange: str = "f32",
         schedule: str = "sync",
         microbatches: int = 1,

@@ -17,7 +17,10 @@ Kernels: ``cdsgd_update_2d`` (Algorithm 1), ``cdmsgd_update_2d``
 emits the next lookahead point ``x' + mu v'`` in the same sweep), and
 ``cdadam_update_2d`` (beyond-paper: consensus mixing with local Adam
 moments).  All take ``neighbors (S, rows, 128)`` + ``weights (S,)`` where
-``S`` = stencil size (degree + self), and run ``interpret=True`` on CPU.
+``S`` = stencil size (degree + self); their scalar operands (weights,
+step sizes, the quantize seed) ride in SMEM as ``(1, n)`` arrays.  They
+compile with Mosaic on a TPU and run in the Pallas interpreter elsewhere
+(:func:`repro.kernels.resolve_interpret`).
 
 Two perf levers ride on every kernel:
 

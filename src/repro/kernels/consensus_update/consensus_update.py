@@ -46,13 +46,14 @@ into the self-separated f32 accumulator, so the neighbor mix reads
 dense decompressed buffer is never materialized in HBM.  The compact
 operands stay resident across the row-block grid (constant index_map);
 each grid step masks the flat indices into its own block's element range
-``[row0 * 128, (row0 + block_rows) * 128)`` using a per-block ``row0``
-OPERAND — like the quantize seeds, ``pl.program_id`` would silently
-re-bind under the stacked mode's vmap over agents.  The in-kernel scatter
-is a value-level ``.at[].add`` on the flattened VMEM tile (exact under
-interpret mode; a compiled TPU lowering routes it through Mosaic's
-scatter support or falls back to XLA outside the kernel — this container
-runs interpret).  The dense gather-dequant path
+``[row0 * 128, (row0 + block_rows) * 128)`` with ``row0`` from
+``pl.program_id(0)`` (the row-block index also under the stacked mode's
+vmap over agents, which prepends a vmapped grid dim that program_id
+skips).  The in-kernel scatter
+is a value-level ``.at[].add`` on the flattened VMEM tile: exact under
+interpret mode, and refused on a TPU (Mosaic has no scatter-add lowering),
+where the entry points raise instead of falling back.  The dense
+gather-dequant path
 (:func:`repro.kernels.consensus_update.topk.topk_decompress_2d` + the
 dense kernels) stays exported as the reference oracle; the two paths
 agree bit-for-bit at f32 accumulation (tested).
@@ -69,10 +70,14 @@ out, e.g. when a caller reuses the gradient afterwards).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 LANE = 128
 DEFAULT_BLOCK_ROWS = 256
@@ -86,11 +91,24 @@ _QDTYPE = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
 # --------------------------------------------------------------------------
 
 
-# decorrelates the PRNG streams of adjacent row blocks; a per-block seed
-# OPERAND (not `pl.program_id`) keeps the streams correct when the whole
-# pallas_call is vmapped over agents (the batching rule prepends the batch
-# axis to the grid, which would silently re-bind program_id(0)).
+# decorrelates the PRNG streams of adjacent row blocks: block i seeds with
+# ``seed + STRIDE * i``.  ``pl.program_id(0)`` is the row-block index also
+# when the pallas_call is vmapped over agents (the batching rule prepends the
+# batch axis to the grid as a vmapped dim, which program_id skips).
 _SEED_BLOCK_STRIDE = 15485863
+
+
+def _smem_scalars(x, dtype=jnp.float32):
+    """A few scalars as one ``(1, n)`` SMEM operand: ``(BlockSpec, array)``.
+
+    Scalars belong in SMEM, and the 2-D shape keeps the block's last two
+    dims equal to the array's once vmap prepends the agent axis — Mosaic
+    refuses a rank-1 block that is neither the whole array nor a multiple
+    of 128, and a squeezed agent dim over an ``(A, n)`` array.
+    """
+    a = jnp.asarray(x, dtype).reshape(1, -1)
+    return (pl.BlockSpec(a.shape, lambda i: (0, 0),
+                         memory_space=pltpu.SMEM), a)
 
 
 def _quantize_math(xf, u, qmax: float, qdtype):
@@ -111,16 +129,16 @@ def _quantize_math(xf, u, qmax: float, qdtype):
 def _sr_quantize_kernel(seed_ref, x_ref, q_ref, scale_ref, *, qmax: float,
                         stochastic: bool):
     """Per-row (128-lane block) scaled quantization with stochastic rounding."""
-    from jax.experimental.pallas import tpu as pltpu
-
     u = None
     if stochastic:
-        pltpu.prng_seed(seed_ref[0])          # per-block seed operand
-        bits = pltpu.bitcast(pltpu.prng_random_bits(x_ref.shape), jnp.uint32)
-        # top 24 bits: exactly representable in f32, so u stays strictly < 1
-        # (a raw 2^-32 scaling rounds the largest uint32s up to u == 1.0,
-        # which would bias floor(x + u) upward by a full quantization step)
-        u = (bits >> 8).astype(jnp.float32) * (1.0 / 16777216.0)
+        pltpu.prng_seed(seed_ref[0, 0] + _SEED_BLOCK_STRIDE * pl.program_id(0))
+        bits = pltpu.prng_random_bits(x_ref.shape)
+        # top 24 bits (a logical shift of the int32 draw): exactly
+        # representable in f32, so u stays strictly < 1 (a raw 2^-32 scaling
+        # rounds the largest draws up to u == 1.0, which would bias
+        # floor(x + u) upward by a full quantization step)
+        u = jax.lax.shift_right_logical(bits, 8).astype(jnp.float32) \
+            * (1.0 / 16777216.0)
     q, scale = _quantize_math(x_ref[...].astype(jnp.float32), u, qmax,
                               q_ref.dtype)
     q_ref[...] = q
@@ -133,7 +151,7 @@ def sr_quantize_2d(
     *,
     exchange: str = "int8",       # "int8" (stochastic) | "fp8" (nearest)
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> tuple:
     """Quantize a flat bucket for the wire: ``(q, scales)``.
 
@@ -152,7 +170,7 @@ def sr_quantize_2d(
     qmax = _QMAX[exchange]
     qdtype = _QDTYPE[exchange]
     stochastic = exchange == "int8"
-    if interpret:
+    if resolve_interpret(interpret):
         u = None
         if stochastic:
             key = jax.random.PRNGKey(jnp.asarray(seed, jnp.int32))
@@ -162,13 +180,12 @@ def sr_quantize_2d(
     n_blocks = pl.cdiv(rows, block_rows)
     kernel = functools.partial(_sr_quantize_kernel, qmax=qmax,
                                stochastic=stochastic)
-    block_seeds = (jnp.asarray(seed, jnp.int32)
-                   + _SEED_BLOCK_STRIDE * jnp.arange(n_blocks, dtype=jnp.int32))
+    seed_spec, seed_arg = _smem_scalars(seed, jnp.int32)
     return pl.pallas_call(
         kernel,
         grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,)),                 # per-block seed
+            seed_spec,                                          # step seed
             pl.BlockSpec((block_rows, LANE), lambda i: (i, 0)),
         ],
         out_specs=(
@@ -179,8 +196,8 @@ def sr_quantize_2d(
             jax.ShapeDtypeStruct((rows, lane), qdtype),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ),
-        interpret=interpret,
-    )(block_seeds, x)
+        interpret=False,
+    )(seed_arg, x)
 
 
 def sr_dequantize_2d(q: jnp.ndarray, scales: jnp.ndarray,
@@ -208,36 +225,36 @@ def _mix_stencil(w_ref, nbrs_ref, scales_ref, self_ref, n_stencil: int, shape):
     if scales_ref is None:
         acc = jnp.zeros(shape, jnp.float32)
         for s in range(n_stencil):
-            acc += w_ref[s] * nbrs_ref[s].astype(jnp.float32)
+            acc += w_ref[0, s] * nbrs_ref[s].astype(jnp.float32)
         return acc
-    acc = w_ref[0] * self_ref[...].astype(jnp.float32)
+    acc = w_ref[0, 0] * self_ref[...].astype(jnp.float32)
     for s in range(n_stencil):
-        acc += w_ref[s + 1] * (nbrs_ref[s].astype(jnp.float32) * scales_ref[s])
+        acc += w_ref[0, s + 1] * (nbrs_ref[s].astype(jnp.float32) * scales_ref[s])
     return acc
 
 
-def _sparse_stencil(w_ref, row0_ref, vals_ref, idx_ref, sc_ref, self_ref,
+def _sparse_stencil(w_ref, vals_ref, idx_ref, sc_ref, self_ref,
                     n_stencil: int, shape):
     """f32 mixing accumulation over top-k compact neighbor payloads.
 
     The self tile stays dense at ``weights[0]`` exactly like the quantized
     form; each neighbor contributes ``w[s+1] * scale * dequant(value)``
-    scatter-accumulated at its flat dense indices.  ``row0_ref`` holds this
-    grid step's first dense row (a per-block operand, NOT ``program_id`` —
-    see the quantize-seed comment above): indices outside the block's
-    element range are masked to contribute 0.0 at position 0, so a compact
-    element lands in exactly one grid step.  Per element the accumulation
-    order matches the dense oracle (stencil-major, f32), so the two forms
-    agree bit-for-bit.
+    scatter-accumulated at its flat dense indices.  This grid step owns the
+    dense rows from ``program_id(0) * block_rows`` (the row-block index also
+    under the stacked mode's vmap — see the quantize-seed comment above):
+    indices outside the block's element range are masked to contribute 0.0
+    at position 0, so a compact element lands in exactly one grid step.
+    Per element the accumulation order matches the dense oracle
+    (stencil-major, f32), so the two forms agree bit-for-bit.
     """
     block_elems = shape[0] * shape[1]
-    acc = (w_ref[0] * self_ref[...].astype(jnp.float32)).reshape(block_elems)
-    base = row0_ref[0] * LANE
+    acc = (w_ref[0, 0] * self_ref[...].astype(jnp.float32)).reshape(block_elems)
+    base = pl.program_id(0) * block_elems
     for s in range(n_stencil):
         deq = vals_ref[s].astype(jnp.float32) * sc_ref[s]   # (k_rows, 128)
         li = idx_ref[s].reshape(-1) - base
         ok = (li >= 0) & (li < block_elems)
-        contrib = jnp.where(ok, w_ref[s + 1] * deq.reshape(-1), 0.0)
+        contrib = jnp.where(ok, w_ref[0, s + 1] * deq.reshape(-1), 0.0)
         acc = acc.at[jnp.where(ok, li, 0)].add(contrib)
     return acc.reshape(shape)
 
@@ -246,7 +263,7 @@ def _cdsgd_body(w_ref, alpha_ref, nbrs_ref, scales_ref, self_ref, grad_ref,
                 out_ref, *, n_stencil: int):
     acc = _mix_stencil(w_ref, nbrs_ref, scales_ref, self_ref, n_stencil,
                        out_ref.shape)
-    acc -= alpha_ref[0] * grad_ref[...].astype(jnp.float32)
+    acc -= alpha_ref[0, 0] * grad_ref[...].astype(jnp.float32)
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
@@ -260,8 +277,8 @@ def _cdsgd_kernel_q(w, a, slf, nbrs, scales, grad, out, *, n_stencil):
 
 def _cdmsgd_body(w_ref, alpha_ref, mu_ref, nbrs_ref, scales_ref, self_ref,
                  grad_ref, mom_ref, out_ref, new_mom_ref, *, n_stencil: int):
-    v = mu_ref[0] * mom_ref[...].astype(jnp.float32) \
-        - alpha_ref[0] * grad_ref[...].astype(jnp.float32)
+    v = mu_ref[0, 0] * mom_ref[...].astype(jnp.float32) \
+        - alpha_ref[0, 0] * grad_ref[...].astype(jnp.float32)
     acc = _mix_stencil(w_ref, nbrs_ref, scales_ref, self_ref, n_stencil,
                        out_ref.shape)
     out_ref[...] = (acc + v).astype(out_ref.dtype)
@@ -289,7 +306,7 @@ def _cdmsgd_kernel_qm(w, a, m, slf, nbrs, scales, vnbrs, vscales, grad, mom,
     crossed the wire), mixed at ``weights[0]`` exactly like the params.
     """
     vmix = _mix_stencil(w, vnbrs, vscales, mom, n_stencil, out.shape)
-    v = m[0] * vmix - a[0] * grad[...].astype(jnp.float32)
+    v = m[0, 0] * vmix - a[0, 0] * grad[...].astype(jnp.float32)
     acc = _mix_stencil(w, nbrs, scales, slf, n_stencil, out.shape)
     out[...] = (acc + v).astype(out.dtype)
     nmom[...] = v.astype(nmom.dtype)
@@ -304,9 +321,9 @@ def _cdmsgd_nesterov_body(w_ref, alpha_ref, mu_ref, nbrs_ref, scales_ref,
     emitting it here saves the separate ``tree_axpy`` HBM pass the unfused
     path pays before every backward.
     """
-    mu = mu_ref[0]
+    mu = mu_ref[0, 0]
     v = mu * mom_ref[...].astype(jnp.float32) \
-        - alpha_ref[0] * grad_ref[...].astype(jnp.float32)
+        - alpha_ref[0, 0] * grad_ref[...].astype(jnp.float32)
     acc = _mix_stencil(w_ref, nbrs_ref, scales_ref, self_ref, n_stencil,
                        out_ref.shape)
     x = acc + v
@@ -331,9 +348,9 @@ def _cdmsgd_nesterov_kernel_qm(w, a, m, slf, nbrs, scales, vnbrs, vscales,
                                grad, mom, out, nmom, look, *, n_stencil):
     """Mixed-momentum Nesterov: the momentum mix feeds both the update and
     the emitted lookahead ``x' + mu v'`` in the same sweep."""
-    mu = m[0]
+    mu = m[0, 0]
     vmix = _mix_stencil(w, vnbrs, vscales, mom, n_stencil, out.shape)
-    v = mu * vmix - a[0] * grad[...].astype(jnp.float32)
+    v = mu * vmix - a[0, 0] * grad[...].astype(jnp.float32)
     acc = _mix_stencil(w, nbrs, scales, slf, n_stencil, out.shape)
     x = acc + v
     out[...] = x.astype(out.dtype)
@@ -349,7 +366,7 @@ def _cdadam_body(w_ref, scal_ref, nbrs_ref, scales_ref, self_ref, grad_ref,
     ``scal_ref`` packs [alpha, b1, b2, eps, bc1, bc2] — the bias corrections
     ``bc = 1 - beta^t`` depend on the (traced) step and are computed outside.
     """
-    alpha, b1, b2, eps, bc1, bc2 = (scal_ref[i] for i in range(6))
+    alpha, b1, b2, eps, bc1, bc2 = (scal_ref[0, i] for i in range(6))
     g = grad_ref[...].astype(jnp.float32)
     m = b1 * m_ref[...].astype(jnp.float32) + (1.0 - b1) * g
     v = b2 * v_ref[...].astype(jnp.float32) + (1.0 - b2) * g * g
@@ -376,7 +393,7 @@ def _cdadam_kernel_qm(w, scal, slf, nbrs, scales, mnbrs, mscales, grad, m, v,
                       out, nm, nv, *, n_stencil):
     """Mixed-momentum CDAdam: ``m' = b1 (Pi m) + (1-b1) g``; the second
     moment stays local (a positive scale, not a direction)."""
-    alpha, b1, b2, eps, bc1, bc2 = (scal[i] for i in range(6))
+    alpha, b1, b2, eps, bc1, bc2 = (scal[0, i] for i in range(6))
     g = grad[...].astype(jnp.float32)
     mmix = _mix_stencil(w, mnbrs, mscales, m, n_stencil, out.shape)
     new_m = b1 * mmix + (1.0 - b1) * g
@@ -388,44 +405,50 @@ def _cdadam_kernel_qm(w, scal, slf, nbrs, scales, mnbrs, mscales, grad, m, v,
     nv[...] = new_v.astype(nv.dtype)
 
 
-def _cdsgd_kernel_s(w, a, row0, slf, vals, idx, sc, grad, out, *, n_stencil):
-    acc = _sparse_stencil(w, row0, vals, idx, sc, slf, n_stencil, out.shape)
-    acc -= a[0] * grad[...].astype(jnp.float32)
+def _cdsgd_kernel_s(w, a, slf, vals, idx, sc, grad, out, *, n_stencil):
+    acc = _sparse_stencil(w, vals, idx, sc, slf, n_stencil, out.shape)
+    acc -= a[0, 0] * grad[...].astype(jnp.float32)
     out[...] = acc.astype(out.dtype)
 
 
-def _cdmsgd_kernel_s(w, a, m, row0, slf, vals, idx, sc, grad, mom, out, nmom,
+def _cdmsgd_kernel_s(w, a, m, slf, vals, idx, sc, grad, mom, out, nmom,
                      *, n_stencil):
-    v = m[0] * mom[...].astype(jnp.float32) \
-        - a[0] * grad[...].astype(jnp.float32)
-    acc = _sparse_stencil(w, row0, vals, idx, sc, slf, n_stencil, out.shape)
+    v = m[0, 0] * mom[...].astype(jnp.float32) \
+        - a[0, 0] * grad[...].astype(jnp.float32)
+    acc = _sparse_stencil(w, vals, idx, sc, slf, n_stencil, out.shape)
     out[...] = (acc + v).astype(out.dtype)
     nmom[...] = v.astype(nmom.dtype)
 
 
-def _cdmsgd_nesterov_kernel_s(w, a, m, row0, slf, vals, idx, sc, grad, mom,
+def _cdmsgd_nesterov_kernel_s(w, a, m, slf, vals, idx, sc, grad, mom,
                               out, nmom, look, *, n_stencil):
-    mu = m[0]
+    mu = m[0, 0]
     v = mu * mom[...].astype(jnp.float32) \
-        - a[0] * grad[...].astype(jnp.float32)
-    acc = _sparse_stencil(w, row0, vals, idx, sc, slf, n_stencil, out.shape)
+        - a[0, 0] * grad[...].astype(jnp.float32)
+    acc = _sparse_stencil(w, vals, idx, sc, slf, n_stencil, out.shape)
     x = acc + v
     out[...] = x.astype(out.dtype)
     nmom[...] = v.astype(nmom.dtype)
     look[...] = (x + mu * v).astype(look.dtype)
 
 
-def _cdadam_kernel_s(w, scal, row0, slf, vals, idx, sc, grad, m, v, out, nm,
+def _cdadam_kernel_s(w, scal, slf, vals, idx, sc, grad, m, v, out, nm,
                      nv, *, n_stencil):
-    alpha, b1, b2, eps, bc1, bc2 = (scal[i] for i in range(6))
+    alpha, b1, b2, eps, bc1, bc2 = (scal[0, i] for i in range(6))
     g = grad[...].astype(jnp.float32)
     new_m = b1 * m[...].astype(jnp.float32) + (1.0 - b1) * g
     new_v = b2 * v[...].astype(jnp.float32) + (1.0 - b2) * g * g
-    acc = _sparse_stencil(w, row0, vals, idx, sc, slf, n_stencil, out.shape)
+    acc = _sparse_stencil(w, vals, idx, sc, slf, n_stencil, out.shape)
     step_dir = (new_m / bc1) / (jnp.sqrt(new_v / bc2) + eps)
     out[...] = (acc - alpha * step_dir).astype(out.dtype)
     nm[...] = new_m.astype(nm.dtype)
     nv[...] = new_v.astype(nv.dtype)
+
+
+def _scalar_operands(*groups):
+    """``(specs, args)`` of the kernels' leading SMEM scalar operands."""
+    specs, args = zip(*(_smem_scalars(g) for g in groups))
+    return list(specs), list(args)
 
 
 def _grid_and_specs(rows: int, block_rows: int, n_stencil: int):
@@ -471,16 +494,22 @@ def _mix_operands(quantized, s, nbr_spec, scale_spec, mat_spec,
 
 
 def _sparse_operands(values, indices, scales, self_buf, grad,
-                     block_rows: int):
+                     block_rows: int, interpret: Optional[bool]):
     """Shared setup of the ``*_update_sparse_2d`` entry points.
 
     Validates the compact-field shapes, builds the grid over the DENSE row
     blocks (the outputs/self/grad are dense — only the neighbor operands
     shrink), and returns ``(grid, mat_spec, sparse_specs, sparse_args,
     s)``: the compact stacks get whole-array BlockSpecs (constant
-    index_map — they stay resident across grid steps) and the per-block
-    ``row0`` operand tells each step which dense element range it owns.
+    index_map — they stay resident across grid steps); each step finds the
+    dense element range it owns from ``pl.program_id(0)``.
     """
+    if not resolve_interpret(interpret):
+        raise NotImplementedError(
+            "the sparse top-k update scatter-adds inside the kernel, and "
+            "Mosaic has no TPU lowering for scatter-add; run the top-k "
+            "compressor with sparse_update=False (the dense "
+            "decompress-then-update kernels) on a TPU")
     s, k_rows, lane = values.shape
     assert lane == LANE, values.shape
     assert indices.shape == (s, k_rows, LANE), (indices.shape, values.shape)
@@ -492,15 +521,13 @@ def _sparse_operands(values, indices, scales, self_buf, grad,
     block_rows = min(block_rows, rows)
     grid = (pl.cdiv(rows, block_rows),)
     mat_spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    row0s = block_rows * jnp.arange(grid[0], dtype=jnp.int32)
     sparse_specs = [
-        pl.BlockSpec((1,), lambda i: (i,)),                    # row0
         mat_spec,                                              # self tile
         pl.BlockSpec((s, k_rows, LANE), lambda i: (0, 0, 0)),  # values
         pl.BlockSpec((s, k_rows, LANE), lambda i: (0, 0, 0)),  # indices
         pl.BlockSpec((s, k_rows, 1), lambda i: (0, 0, 0)),     # scales
     ]
-    sparse_args = [row0s, self_buf, values, indices.astype(jnp.int32), scales]
+    sparse_args = [self_buf, values, indices.astype(jnp.int32), scales]
     return grid, mat_spec, sparse_specs, sparse_args, s
 
 
@@ -515,21 +542,20 @@ def cdsgd_update_sparse_2d(
     self_buf: jnp.ndarray,        # (rows, 128) native self tile
     block_rows: int = DEFAULT_BLOCK_ROWS,
     alias: bool = True,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """CDSGD update consuming the top-k wire directly (see module docs)."""
     grid, mat_spec, sp_specs, sp_args, s = _sparse_operands(
-        values, indices, scales, self_buf, grad, block_rows)
+        values, indices, scales, self_buf, grad, block_rows, interpret)
     assert weights.shape == (s + 1,), (weights.shape, s)
     kernel = functools.partial(_cdsgd_kernel_s, n_stencil=s)
+    sc_specs, sc_args = _scalar_operands(weights, alpha)
     in_specs = [
-        pl.BlockSpec((s + 1,), lambda i: (0,)),    # weights
-        pl.BlockSpec((1,), lambda i: (0,)),        # alpha
+        *sc_specs,                         # weights, alpha
         *sp_specs,
         mat_spec,                                  # grad
     ]
-    args = [weights.astype(jnp.float32), jnp.asarray([alpha], jnp.float32),
-            *sp_args, grad]
+    args = [*sc_args, *sp_args, grad]
     grad_idx = len(args) - 1
     return pl.pallas_call(
         kernel,
@@ -538,7 +564,7 @@ def cdsgd_update_sparse_2d(
         out_specs=mat_spec,
         out_shape=jax.ShapeDtypeStruct(grad.shape, grad.dtype),
         input_output_aliases=_aliases(alias, ((grad_idx, 0),)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
 
 
@@ -555,23 +581,21 @@ def cdmsgd_update_sparse_2d(
     self_buf: jnp.ndarray,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     alias: bool = True,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ):
     """CDMSGD update on the sparse operand form (local momentum only — the
     top-k programs exclude ``momentum_mixing`` at config time)."""
     grid, mat_spec, sp_specs, sp_args, s = _sparse_operands(
-        values, indices, scales, self_buf, grad, block_rows)
+        values, indices, scales, self_buf, grad, block_rows, interpret)
     assert weights.shape == (s + 1,), (weights.shape, s)
     kernel = functools.partial(_cdmsgd_kernel_s, n_stencil=s)
+    sc_specs, sc_args = _scalar_operands(weights, alpha, mu)
     in_specs = [
-        pl.BlockSpec((s + 1,), lambda i: (0,)),    # weights
-        pl.BlockSpec((1,), lambda i: (0,)),        # alpha
-        pl.BlockSpec((1,), lambda i: (0,)),        # mu
+        *sc_specs,                         # weights, alpha, mu
         *sp_specs,
         mat_spec, mat_spec,                        # grad, momentum
     ]
-    args = [weights.astype(jnp.float32), jnp.asarray([alpha], jnp.float32),
-            jnp.asarray([mu], jnp.float32), *sp_args, grad, momentum]
+    args = [*sc_args, *sp_args, grad, momentum]
     g_idx = len(args) - 2
     return pl.pallas_call(
         kernel,
@@ -583,7 +607,7 @@ def cdmsgd_update_sparse_2d(
             jax.ShapeDtypeStruct(momentum.shape, momentum.dtype),
         ),
         input_output_aliases=_aliases(alias, ((g_idx, 0), (g_idx + 1, 1))),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
 
 
@@ -600,23 +624,21 @@ def cdmsgd_nesterov_update_sparse_2d(
     self_buf: jnp.ndarray,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     alias: bool = True,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ):
     """Returns ``(x', v', x' + mu v')`` like the dense Nesterov form, with
     the neighbor mix on the sparse operands."""
     grid, mat_spec, sp_specs, sp_args, s = _sparse_operands(
-        values, indices, scales, self_buf, grad, block_rows)
+        values, indices, scales, self_buf, grad, block_rows, interpret)
     assert weights.shape == (s + 1,), (weights.shape, s)
     kernel = functools.partial(_cdmsgd_nesterov_kernel_s, n_stencil=s)
+    sc_specs, sc_args = _scalar_operands(weights, alpha, mu)
     in_specs = [
-        pl.BlockSpec((s + 1,), lambda i: (0,)),    # weights
-        pl.BlockSpec((1,), lambda i: (0,)),        # alpha
-        pl.BlockSpec((1,), lambda i: (0,)),        # mu
+        *sc_specs,                         # weights, alpha, mu
         *sp_specs,
         mat_spec, mat_spec,                        # grad, momentum
     ]
-    args = [weights.astype(jnp.float32), jnp.asarray([alpha], jnp.float32),
-            jnp.asarray([mu], jnp.float32), *sp_args, grad, momentum]
+    args = [*sc_args, *sp_args, grad, momentum]
     g_idx = len(args) - 2
     return pl.pallas_call(
         kernel,
@@ -629,7 +651,7 @@ def cdmsgd_nesterov_update_sparse_2d(
             jax.ShapeDtypeStruct(grad.shape, grad.dtype),
         ),
         input_output_aliases=_aliases(alias, ((g_idx, 0), (g_idx + 1, 1))),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
 
 
@@ -651,22 +673,22 @@ def cdadam_update_sparse_2d(
     self_buf: jnp.ndarray,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     alias: bool = True,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ):
     """Returns ``(x', m', v')`` — local Adam moments, sparse neighbor mix."""
     grid, mat_spec, sp_specs, sp_args, s = _sparse_operands(
-        values, indices, scales, self_buf, grad, block_rows)
+        values, indices, scales, self_buf, grad, block_rows, interpret)
     assert weights.shape == (s + 1,), (weights.shape, s)
     kernel = functools.partial(_cdadam_kernel_s, n_stencil=s)
     scal = jnp.stack([jnp.asarray(x, jnp.float32) for x in
                       (alpha, b1, b2, eps, bc1, bc2)])
+    sc_specs, sc_args = _scalar_operands(weights, scal)
     in_specs = [
-        pl.BlockSpec((s + 1,), lambda i: (0,)),    # weights
-        pl.BlockSpec((6,), lambda i: (0,)),        # packed scalars
+        *sc_specs,                         # weights, packed scalars
         *sp_specs,
         mat_spec, mat_spec, mat_spec,              # grad, m, v
     ]
-    args = [weights.astype(jnp.float32), scal, *sp_args, grad, m, v]
+    args = [*sc_args, *sp_args, grad, m, v]
     g_idx = len(args) - 3
     return pl.pallas_call(
         kernel,
@@ -680,7 +702,7 @@ def cdadam_update_sparse_2d(
         ),
         input_output_aliases=_aliases(
             alias, ((g_idx, 0), (g_idx + 1, 1), (g_idx + 2, 2))),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
 
 
@@ -694,7 +716,7 @@ def cdsgd_update_2d(
     self_buf: jnp.ndarray = None, # (rows, 128) native self tile (quantized form)
     block_rows: int = DEFAULT_BLOCK_ROWS,
     alias: bool = True,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     s, rows, lane = neighbors.shape
     assert lane == LANE and grad.shape == (rows, lane)
@@ -706,14 +728,13 @@ def cdsgd_update_2d(
     mix_specs, mix_args, n_w = _mix_operands(
         quantized, s, nbr_spec, scale_spec, mat_spec, neighbors, scales, self_buf)
     assert weights.shape == (n_w,)
+    sc_specs, sc_args = _scalar_operands(weights, alpha)
     in_specs = [
-        pl.BlockSpec((n_w,), lambda i: (0,)),      # weights (whole, tiny)
-        pl.BlockSpec((1,), lambda i: (0,)),        # alpha
+        *sc_specs,                         # weights, alpha
         *mix_specs,
         mat_spec,                                  # grad
     ]
-    args = [weights.astype(jnp.float32), jnp.asarray([alpha], jnp.float32),
-            *mix_args, grad]
+    args = [*sc_args, *mix_args, grad]
     grad_idx = len(args) - 1
     return pl.pallas_call(
         kernel,
@@ -722,7 +743,7 @@ def cdsgd_update_2d(
         out_specs=mat_spec,
         out_shape=jax.ShapeDtypeStruct((rows, lane), grad.dtype),
         input_output_aliases=_aliases(alias, ((grad_idx, 0),)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
 
 
@@ -740,7 +761,7 @@ def cdmsgd_update_2d(
     mom_scales: jnp.ndarray = None,      # (S, rows, 1) momentum row scales
     block_rows: int = DEFAULT_BLOCK_ROWS,
     alias: bool = True,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ):
     """``mom_neighbors`` (+ ``mom_scales``) selects the mixed-momentum form
     ``v' = mu (Pi v) - a g``: the momentum buffer crossed the wire like the
@@ -756,15 +777,13 @@ def cdmsgd_update_2d(
     mix_specs, mix_args, n_w = _mix_operands(
         quantized, s, nbr_spec, scale_spec, mat_spec, neighbors, scales,
         self_buf, mom_neighbors, mom_scales)
+    sc_specs, sc_args = _scalar_operands(weights, alpha, mu)
     in_specs = [
-        pl.BlockSpec((n_w,), lambda i: (0,)),      # weights
-        pl.BlockSpec((1,), lambda i: (0,)),        # alpha
-        pl.BlockSpec((1,), lambda i: (0,)),        # mu
+        *sc_specs,                         # weights, alpha, mu
         *mix_specs,
         mat_spec, mat_spec,                        # grad, momentum
     ]
-    args = [weights.astype(jnp.float32), jnp.asarray([alpha], jnp.float32),
-            jnp.asarray([mu], jnp.float32), *mix_args, grad, momentum]
+    args = [*sc_args, *mix_args, grad, momentum]
     g_idx = len(args) - 2
     return pl.pallas_call(
         kernel,
@@ -776,7 +795,7 @@ def cdmsgd_update_2d(
             jax.ShapeDtypeStruct((rows, lane), momentum.dtype),
         ),
         input_output_aliases=_aliases(alias, ((g_idx, 0), (g_idx + 1, 1))),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
 
 
@@ -794,7 +813,7 @@ def cdmsgd_nesterov_update_2d(
     mom_scales: jnp.ndarray = None,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     alias: bool = True,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ):
     """Returns ``(x', v', x' + mu v')`` — params, momentum, next lookahead.
 
@@ -814,15 +833,13 @@ def cdmsgd_nesterov_update_2d(
     mix_specs, mix_args, n_w = _mix_operands(
         quantized, s, nbr_spec, scale_spec, mat_spec, neighbors, scales,
         self_buf, mom_neighbors, mom_scales)
+    sc_specs, sc_args = _scalar_operands(weights, alpha, mu)
     in_specs = [
-        pl.BlockSpec((n_w,), lambda i: (0,)),      # weights
-        pl.BlockSpec((1,), lambda i: (0,)),        # alpha
-        pl.BlockSpec((1,), lambda i: (0,)),        # mu
+        *sc_specs,                         # weights, alpha, mu
         *mix_specs,
         mat_spec, mat_spec,                        # grad, momentum
     ]
-    args = [weights.astype(jnp.float32), jnp.asarray([alpha], jnp.float32),
-            jnp.asarray([mu], jnp.float32), *mix_args, grad, momentum]
+    args = [*sc_args, *mix_args, grad, momentum]
     g_idx = len(args) - 2
     return pl.pallas_call(
         kernel,
@@ -835,7 +852,7 @@ def cdmsgd_nesterov_update_2d(
             jax.ShapeDtypeStruct((rows, lane), grad.dtype),
         ),
         input_output_aliases=_aliases(alias, ((g_idx, 0), (g_idx + 1, 1))),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
 
 
@@ -858,7 +875,7 @@ def cdadam_update_2d(
     mom_scales: jnp.ndarray = None,
     block_rows: int = DEFAULT_BLOCK_ROWS,
     alias: bool = True,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ):
     """Returns ``(x', m', v')`` — mixed params with a local-Adam step.
     ``mom_neighbors`` mixes the first moment over the wire too
@@ -876,13 +893,13 @@ def cdadam_update_2d(
     mix_specs, mix_args, n_w = _mix_operands(
         quantized, s, nbr_spec, scale_spec, mat_spec, neighbors, scales,
         self_buf, mom_neighbors, mom_scales)
+    sc_specs, sc_args = _scalar_operands(weights, scal)
     in_specs = [
-        pl.BlockSpec((n_w,), lambda i: (0,)),      # weights
-        pl.BlockSpec((6,), lambda i: (0,)),        # packed scalars
+        *sc_specs,                         # weights, packed scalars
         *mix_specs,
         mat_spec, mat_spec, mat_spec,              # grad, m, v
     ]
-    args = [weights.astype(jnp.float32), scal, *mix_args, grad, m, v]
+    args = [*sc_args, *mix_args, grad, m, v]
     g_idx = len(args) - 3
     return pl.pallas_call(
         kernel,
@@ -896,5 +913,5 @@ def cdadam_update_2d(
         ),
         input_output_aliases=_aliases(
             alias, ((g_idx, 0), (g_idx + 1, 1), (g_idx + 2, 2))),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)

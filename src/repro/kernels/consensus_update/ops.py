@@ -29,17 +29,18 @@ buffer rides in ``self_buf`` at ``weights[0]`` (per-agent ``(A, rows, 128)``
 in the stacked mode, with ``weights (A, A+1)`` = ``[diag(Pi), off-diag
 rows]``), since the local parameters never cross the wire.
 
-On CPU (this container) the kernels run with ``interpret=True``; on TPU
-pass ``interpret=False`` for the compiled path.
+``interpret`` defaults to ``None``: :func:`repro.kernels.resolve_interpret`
+runs the compiled kernels on a TPU and the Pallas interpreter elsewhere.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, List, NamedTuple, Sequence, Tuple
+from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.core import flatbuf
 from repro.kernels.consensus_update.consensus_update import (
@@ -80,8 +81,8 @@ def _eqn_sub_jaxprs(params: dict):
     for v in params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for x in vals:
-            if isinstance(x, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
-                yield x.jaxpr if isinstance(x, jax.core.ClosedJaxpr) else x
+            if isinstance(x, (Jaxpr, ClosedJaxpr)):
+                yield x.jaxpr if isinstance(x, ClosedJaxpr) else x
 
 
 def alias_groups(jaxpr) -> List[List[Tuple[int, int]]]:
@@ -101,7 +102,7 @@ def alias_groups(jaxpr) -> List[List[Tuple[int, int]]]:
         raise TypeError(
             "alias_groups walks jaxpr eqns structurally; pass the jaxpr "
             "object from jax.make_jaxpr(...), not its printed text")
-    j = jaxpr.jaxpr if isinstance(jaxpr, jax.core.ClosedJaxpr) else jaxpr
+    j = jaxpr.jaxpr if isinstance(jaxpr, ClosedJaxpr) else jaxpr
     out: List[List[Tuple[int, int]]] = []
 
     def walk(jx):
@@ -123,7 +124,7 @@ def alias_groups(jaxpr) -> List[List[Tuple[int, int]]]:
 
 
 def cdsgd_update_flat(neighbors, weights, grad, alpha, *, scales=None,
-                      self_buf=None, interpret: bool = True):
+                      self_buf=None, interpret: Optional[bool] = None):
     if isinstance(neighbors, SparseNeighbors):
         nb = neighbors
         if weights.ndim == 2:
@@ -146,7 +147,7 @@ def cdsgd_update_flat(neighbors, weights, grad, alpha, *, scales=None,
 
 def cdmsgd_update_flat(neighbors, weights, grad, momentum, alpha, mu, *,
                        scales=None, self_buf=None, mom_neighbors=None,
-                       mom_scales=None, interpret: bool = True):
+                       mom_scales=None, interpret: Optional[bool] = None):
     if isinstance(neighbors, SparseNeighbors):
         nb = neighbors
         if weights.ndim == 2:
@@ -181,7 +182,7 @@ def cdmsgd_update_flat(neighbors, weights, grad, momentum, alpha, mu, *,
 def cdmsgd_nesterov_update_flat(neighbors, weights, grad, momentum, alpha, mu,
                                 *, scales=None, self_buf=None,
                                 mom_neighbors=None, mom_scales=None,
-                                interpret: bool = True):
+                                interpret: Optional[bool] = None):
     if isinstance(neighbors, SparseNeighbors):
         nb = neighbors
         if weights.ndim == 2:
@@ -217,7 +218,7 @@ def cdmsgd_nesterov_update_flat(neighbors, weights, grad, momentum, alpha, mu,
 def cdadam_update_flat(neighbors, weights, grad, m, v, alpha, b1, b2, eps,
                        bc1, bc2, *, scales=None, self_buf=None,
                        mom_neighbors=None, mom_scales=None,
-                       interpret: bool = True):
+                       interpret: Optional[bool] = None):
     if isinstance(neighbors, SparseNeighbors):
         nb = neighbors
         if weights.ndim == 2:
@@ -273,7 +274,7 @@ def cdsgd_update_tree(
     grad_tree: PyTree,
     alpha,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> PyTree:
     spec = flatbuf.make_flat_spec(self_tree)
     stacked, (grads,) = _pack_all(spec, self_tree, neighbor_trees, grad_tree)
@@ -292,7 +293,7 @@ def cdmsgd_update_tree(
     alpha,
     mu,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     spec = flatbuf.make_flat_spec(self_tree)
     stacked, (grads, moms) = _pack_all(
@@ -314,7 +315,7 @@ def cdmsgd_nesterov_update_tree(
     alpha,
     mu,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns ``(params', momentum', lookahead')`` in one sweep per bucket."""
     spec = flatbuf.make_flat_spec(self_tree)
@@ -344,7 +345,7 @@ def cdadam_update_tree(
     bc1,
     bc2,
     *,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns ``(params', m', v')``; moments stay local, params mix."""
     spec = flatbuf.make_flat_spec(self_tree)

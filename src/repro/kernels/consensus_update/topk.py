@@ -62,10 +62,13 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 from repro.kernels.consensus_update.consensus_update import (
     DEFAULT_BLOCK_ROWS,
@@ -187,7 +190,7 @@ def _threshold_count_kernel(taus_ref, x_ref, counts_ref, *, n_bins: int,
 def topk_threshold_2d(x: jnp.ndarray, k: int, *, n_bins: int = 16,
                       span: float = 1e-4,
                       block_rows: int = DEFAULT_BLOCK_ROWS,
-                      interpret: bool = False):
+                      interpret: Optional[bool] = None):
     """Bracket the k-th largest magnitude of a flat bucket in ONE sweep.
 
     Sweeps the ``(rows, 128)`` bucket once, counting ``|x| >= tau_b`` for
@@ -224,7 +227,7 @@ def topk_threshold_2d(x: jnp.ndarray, k: int, *, n_bins: int = 16,
         ],
         out_specs=pl.BlockSpec((1, n_bins), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, n_bins), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(taus, x)[0]
     # counts are nondecreasing in b (taus decreasing); pick the smallest
     # tau still selecting <= k elements — prefix-sum of the <=k mask
@@ -240,7 +243,7 @@ def topk_threshold_2d(x: jnp.ndarray, k: int, *, n_bins: int = 16,
 
 def topk_compress_2d(x: jnp.ndarray, k_rows: int, seed, *,
                      block_rows: int = DEFAULT_BLOCK_ROWS,
-                     interpret: bool = False):
+                     interpret: Optional[bool] = None):
     """Compress one dense bucket to its lane-aligned top-K compact form.
 
     Returns ``(values, indices, scales)``: int8 ``(k_rows, 128)`` compact

@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -81,7 +83,7 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     b, h, sq, d = q.shape
     kv, sk = k.shape[1], k.shape[2]
@@ -119,6 +121,6 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),    # running sum
             pltpu.VMEM((bq, d), jnp.float32),    # output accumulator
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d)

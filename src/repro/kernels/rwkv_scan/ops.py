@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +12,7 @@ from repro.kernels.rwkv_scan.rwkv_scan import wkv6_pallas
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6_bsnh(r, k, v, w, u, *, chunk: int = 128, interpret: bool = True):
+def wkv6_bsnh(r, k, v, w, u, *, chunk: int = 128, interpret: Optional[bool] = None):
     """r,k,v,w: (b, s, n_h, hs); u: (n_h, hs).
 
     Returns (y (b, s, n_h, hs), state (b, n_h, hs, hs)) — drop-in for

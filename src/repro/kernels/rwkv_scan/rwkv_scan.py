@@ -20,11 +20,14 @@ Validated in ``interpret=True`` against :func:`repro.nn.ssm.wkv6_scan`.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
+
+from repro.kernels import resolve_interpret
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_out_ref, s_scr,
@@ -72,7 +75,7 @@ def wkv6_pallas(
     u: jnp.ndarray,          # (BH, hs) per-head bonus (broadcast over batch)
     *,
     chunk: int = 128,
-    interpret: bool = False,
+    interpret: Optional[bool] = None,
 ):
     """Returns (y (BH, S, hs), final_state (BH, hs, hs))."""
     bh, s, hs = r.shape
@@ -99,6 +102,6 @@ def wkv6_pallas(
             jax.ShapeDtypeStruct((bh, hs, hs), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((hs, hs), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(r, k, v, w, u)
     return y, s_fin

@@ -11,11 +11,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across versions: 0.4.x has no ``axis_types`` kwarg."""
-    if hasattr(jax.sharding, "AxisType"):   # jax >= 0.5
-        auto = (jax.sharding.AxisType.Auto,) * len(axes)
-        return jax.make_mesh(shape, axes, axis_types=auto)
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -32,6 +32,9 @@ def main() -> None:
     from repro.configs import get_config
     from repro.nn import (model_template, init_params, init_cache, decode_step,
                           encode_for_decode)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.preset == "tiny":

@@ -77,15 +77,6 @@ P = PartitionSpec
 PyTree = Any
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    if hasattr(jax, "shard_map"):          # jax >= 0.6
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm  # jax 0.4.x
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 @dataclasses.dataclass
 class TrainStepBundle:
     step_fn: Callable                     # (params, opt_state, batch) -> (params, opt_state, metrics)
@@ -138,7 +129,8 @@ def _agent_factors(mesh: Mesh, agent_axes) -> consensus_lib.FactoredMix:
 
 
 def make_local_fused_comm(
-    topology: Topology, mesh: Mesh, mode: str, *, interpret: bool = True,
+    topology: Topology, mesh: Mesh, mode: str, *,
+    interpret: Optional[bool] = None,
     exchange: str = "f32",
     program: Optional[consensus_lib.MixingProgram] = None,
 ) -> CommOps:
@@ -205,10 +197,12 @@ def make_mix_comm(
     local_mean = consensus_lib.make_sharded_mean_fn(ax)
 
     def mix(tree: PyTree) -> PyTree:
-        return _shard_map(local_mix, mesh, (param_specs,), param_specs)(tree)
+        return jax.shard_map(local_mix, mesh=mesh, in_specs=(param_specs,),
+                             out_specs=param_specs, check_vma=False)(tree)
 
     def mean(tree: PyTree) -> PyTree:
-        return _shard_map(local_mean, mesh, (param_specs,), param_specs)(tree)
+        return jax.shard_map(local_mean, mesh=mesh, in_specs=(param_specs,),
+                             out_specs=param_specs, check_vma=False)(tree)
 
     return CommOps(mix=mix, mean=mean, n_agents=n_agents, lambda2=lam2, lambdan=lamn)
 
@@ -224,7 +218,7 @@ def build_train_step(
     mixing: str = "dense",
     remat: bool = True,
     microbatches: int = 1,
-    interpret: bool = True,       # Pallas interpret mode (fused path; False on TPU)
+    interpret: Optional[bool] = None,  # Pallas interpret mode; None: by backend
     exchange: str = "f32",        # ppermute wire precision (fused path only)
     schedule: str = "sync",       # exchange schedule: sync | overlap
     mixing_strategy: str = "static",   # static | time_varying | multi_round
@@ -321,8 +315,8 @@ def build_train_step(
         local_residual_init = engine.make_local_residual_init(comm.flat)
 
         def init_residual(params):
-            return _shard_map(local_residual_init, mesh, (pspecs,),
-                              residual_specs)(params)
+            return jax.shard_map(local_residual_init, mesh=mesh, in_specs=(pspecs,),
+                                 out_specs=residual_specs, check_vma=False)(params)
 
     if program.compressed and program.compressor_kind == "rank":
         # the rank compressor's warm-start bases ride the optimizer state
@@ -334,8 +328,8 @@ def build_train_step(
         local_qwarm_init = engine.make_local_qwarm_init(comm.flat)
 
         def init_qwarm(params):
-            return _shard_map(local_qwarm_init, mesh, (pspecs,),
-                              qwarm_specs)(params)
+            return jax.shard_map(local_qwarm_init, mesh=mesh, in_specs=(pspecs,),
+                                 out_specs=qwarm_specs, check_vma=False)(params)
 
     if schedule == "overlap":
         if mixing != "ppermute_fused":
@@ -380,17 +374,17 @@ def build_train_step(
         local_wire_init = engine.make_local_wire_init(fl)
 
         def init_wire(params):
-            return _shard_map(local_wire_init, mesh, (pspecs,),
-                              wire_specs)(params)
+            return jax.shard_map(local_wire_init, mesh=mesh, in_specs=(pspecs,),
+                                 out_specs=wire_specs, check_vma=False)(params)
 
     grad_phase = engine.make_grad_phase(
         lambda p, b: loss_fn(cfg, p, b, remat=remat), microbatches)
     update_local = engine.make_update_phase(optimizer, comm, schedule)
     if mixing == "ppermute_fused":
         def update_phase(params, grads, opt_state):
-            return _shard_map(
-                update_local, mesh,
-                (pspecs, pspecs, opt_specs), (pspecs, opt_specs),
+            return jax.shard_map(
+                update_local, mesh=mesh, in_specs=(pspecs, pspecs, opt_specs),
+                out_specs=(pspecs, opt_specs), check_vma=False,
             )(params, grads, opt_state)
     else:
         update_phase = update_local

@@ -1,8 +1,13 @@
 """Training launcher: collaborative CDSGD training for any --arch.
 
-On real hardware this drives the pjit'd sharded step over the production
-mesh; on this CPU container use ``--preset tiny`` (reduced config,
-simulated agents) which exercises the identical optimizer/consensus code.
+Drives the stacked :class:`repro.core.trainer.CollaborativeTrainer`: every
+agent's parameters carry a leading agent axis on one device, and the fused
+update runs compiled Pallas kernels on a TPU and the Pallas interpreter
+elsewhere (:func:`repro.kernels.resolve_interpret`).  ``--preset tiny``
+is the reduced config for CPU runs; ``--preset full`` keeps the published
+widths.  The sharded step (one agent per chip, neighbours mixed by
+``ppermute``) is :func:`repro.launch.steps.build_train_step`, which
+``chip_smoke.py --chips 4`` and ``repro.launch.dryrun`` drive.
 
 Examples:
   python -m repro.launch.train --arch gemma3-1b --preset tiny --steps 50
@@ -13,15 +18,13 @@ Examples:
 from __future__ import annotations
 
 import argparse
-import functools
-import time
+from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__,
                                  formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--arch", required=True)
@@ -112,23 +115,37 @@ def main() -> None:
                          "(incl. overlap wire buffers / error-feedback "
                          "residuals) from --checkpoint-dir before training")
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    return ap
 
+
+def config_for(args: argparse.Namespace):
+    """The ``ArchConfig`` of ``--arch`` under ``--preset``."""
     from repro.configs import get_config
-    from repro.core import make_topology, make_optimizer, schedules
-    from repro.core.trainer import CollaborativeTrainer, train_loop
-    from repro.data import make_lm_tokens, lm_agent_batches
-    from repro.nn import model_template, init_params, loss_fn, count_params
-    from repro.checkpoint import restore_train_state, save_train_state
 
     cfg = get_config(args.arch)
-    if args.preset == "tiny":
-        cfg = cfg.reduced()
+    return cfg.reduced() if args.preset == "tiny" else cfg
+
+
+def build_trainer(args: argparse.Namespace, cfg, *,
+                  printer: Callable[[str], None] = print):
+    """Everything :func:`main` does before the first step.
+
+    Initializes the parameters of ``cfg`` from ``--seed``, normalizes the
+    flags that imply ``--fused``, builds the optimizer, topology and
+    :class:`CollaborativeTrainer`, reports the mixing program and its wire
+    cost through ``printer``, and returns ``(trainer, batches)`` with
+    ``batches`` the seeded per-agent LM batch stream.  Raises
+    ``ValueError`` for a flag combination the trainer cannot run.
+    """
+    from repro.core import make_topology, make_optimizer, schedules
+    from repro.core.trainer import CollaborativeTrainer
+    from repro.data import make_lm_tokens, lm_agent_batches
+    from repro.nn import model_template, init_params, loss_fn, count_params
 
     template = model_template(cfg)
     params = init_params(template, jax.random.PRNGKey(args.seed))
-    print(f"[train] {cfg.name}: {count_params(template):,} params, "
-          f"{args.agents} agents over {args.topology}")
+    printer(f"[train] {cfg.name}: {count_params(template):,} params, "
+            f"{args.agents} agents over {args.topology}")
 
     sched = (args.lr if args.lr_schedule == "fixed"
              else schedules.diminishing(theta=args.lr * 10, eps=1.0, t=10.0))
@@ -139,24 +156,25 @@ def main() -> None:
         kw["local_steps"] = args.local_steps
     if args.exchange != "f32" and not args.fused:
         # the exchange knob lives on the fused flat-buffer path
-        print(f"[train] --exchange {args.exchange} implies --fused; enabling")
+        printer(f"[train] --exchange {args.exchange} implies --fused; enabling")
         args.fused = True
     if args.schedule == "overlap" and not args.fused:
         # the overlap wire double-buffer lives on the fused flat-buffer path
-        print("[train] --schedule overlap implies --fused; enabling")
+        printer("[train] --schedule overlap implies --fused; enabling")
         args.fused = True
     fault_tolerant = (args.staleness > 1
                       or (args.fault_schedule not in (None, "none")))
     if fault_tolerant and args.schedule != "overlap":
-        ap.error("--staleness > 1 / --fault-schedule need --schedule overlap "
-                 "(the staleness ring generalizes the overlap wire buffer)")
+        raise ValueError(
+            "--staleness > 1 / --fault-schedule need --schedule overlap "
+            "(the staleness ring generalizes the overlap wire buffer)")
     nontrivial_mixing = (args.mixing_strategy != "static"
                          or args.consensus_rounds > 1 or args.error_feedback
                          or args.momentum_mixing != "none" or fault_tolerant
                          or args.compressor != "none")
     if nontrivial_mixing and not args.fused:
         # the strategy layer lives on the fused flat-buffer path
-        print("[train] non-static mixing strategy implies --fused; enabling")
+        printer("[train] non-static mixing strategy implies --fused; enabling")
         args.fused = True
     if args.fused:
         kw["fused"] = True
@@ -169,7 +187,7 @@ def main() -> None:
             extra["frontend"] = jnp.ones(
                 (batch["inputs"].shape[0], cfg.frontend_tokens, cfg.frontend_dim),
                 jnp.float32)
-        return loss_fn(cfg, p, {**batch, **extra})
+        return loss_fn(cfg, p, {**batch, **extra}, remat=True)
 
     trainer = CollaborativeTrainer(lm_loss, params, topo, opt,
                                    exchange=args.exchange,
@@ -190,27 +208,42 @@ def main() -> None:
     from repro.core.consensus import describe_exchange_cost
     program = trainer.program
     if not program.is_trivial:
-        print(f"[train] mixing program: {program.describe()}")
+        printer(f"[train] mixing program: {program.describe()}")
         if not program.schedule.is_static:
             d = program.schedule.diagnostics(program.rounds)
-            print(f"[train] schedule effective gap "
-                  f"{d['effective_gap']:.4f} (per-matrix "
-                  f"{['%.4f' % g for g in d['per_matrix_gap']]})")
+            printer(f"[train] schedule effective gap "
+                    f"{d['effective_gap']:.4f} (per-matrix "
+                    f"{['%.4f' % g for g in d['per_matrix_gap']]})")
     if args.optimizer == "fedavg":
         # FedAvg moves no neighbor traffic — its cost is the whole-model
         # all-reduce once per E sync steps (gated; amortized bytes/E)
-        print(f"[train] fedavg all-reduce: {trainer.wire_bytes_per_step:,} "
-              f"bytes/agent/step amortized (sync every "
-              f"{opt.local_steps} steps"
-              + (", params + momentum averaged" if opt.mu else "") + ")")
+        printer(f"[train] fedavg all-reduce: {trainer.wire_bytes_per_step:,} "
+                f"bytes/agent/step amortized (sync every "
+                f"{opt.local_steps} steps"
+                + (", params + momentum averaged" if opt.mu else "") + ")")
     else:
-        print("[train] " + describe_exchange_cost(
+        printer("[train] " + describe_exchange_cost(
             trainer.state.params,
             program.schedule if not program.schedule.is_static else topo,
             trainer.exchange, rounds=program.rounds,
             payloads=program.n_payloads, program=program))
     tokens = make_lm_tokens(1 << 15, vocab=cfg.vocab_size, seed=args.seed)
     batches = lm_agent_batches(tokens, args.agents, args.batch, args.seq, seed=args.seed)
+    return trainer, batches
+
+
+def main(argv: Optional[Sequence[str]] = None) -> None:
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    from repro.checkpoint import restore_train_state, save_train_state
+    from repro.core.trainer import train_loop
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    try:
+        trainer, batches = build_trainer(args, config_for(args))
+    except ValueError as e:
+        ap.error(str(e))
 
     if args.resume:
         if not args.checkpoint_dir:

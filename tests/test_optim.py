@@ -2,6 +2,7 @@
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 import numpy as np
 import pytest
 
@@ -199,8 +200,8 @@ def test_fedavg_mean_gated_inside_cond(setup):
             if not top_only:
                 for v in eqn.params.values():
                     for x in (v if isinstance(v, (tuple, list)) else (v,)):
-                        if isinstance(x, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
-                            j = x.jaxpr if isinstance(x, jax.core.ClosedJaxpr) else x
+                        if isinstance(x, (Jaxpr, ClosedJaxpr)):
+                            j = x.jaxpr if isinstance(x, ClosedJaxpr) else x
                             n += count_reduces(j, top_only)
         return n
 

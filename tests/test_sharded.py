@@ -726,12 +726,13 @@ def test_sharded_momentum_mixing_acceptance():
                                    for _ in range(n_entries))
                 opt_specs = opt_specs._replace(wire=wire_specs)
                 local_wire_init = engine.make_local_wire_init(comm.flat)
-                init_wire = lambda p: steps_lib._shard_map(
-                    local_wire_init, mesh, (pspecs,), wire_specs)(p)
+                init_wire = lambda p: jax.shard_map(
+                    local_wire_init, mesh=mesh, in_specs=(pspecs,),
+                    out_specs=wire_specs, check_vma=False)(p)
             update_local = engine.make_update_phase(opt, comm, schedule)
-            update_phase = lambda p, g, s: steps_lib._shard_map(
-                update_local, mesh, (pspecs, pspecs, opt_specs),
-                (pspecs, opt_specs))(p, g, s)
+            update_phase = lambda p, g, s: jax.shard_map(
+                update_local, mesh=mesh, in_specs=(pspecs, pspecs, opt_specs),
+                out_specs=(pspecs, opt_specs), check_vma=False)(p, g, s)
             return engine.StepProgram(
                 optimizer=opt, comm=comm,
                 grad_phase=engine.make_grad_phase(LOSS),
@@ -939,12 +940,13 @@ def test_sharded_bounded_staleness_acceptance():
                                    for _ in range(n_entries))
             opt_specs = opt_specs._replace(wire=wire_specs)
             local_wire_init = engine.make_local_wire_init(comm.flat)
-            init_wire = lambda p: steps_lib._shard_map(
-                local_wire_init, mesh, (pspecs,), wire_specs)(p)
+            init_wire = lambda p: jax.shard_map(
+                local_wire_init, mesh=mesh, in_specs=(pspecs,),
+                out_specs=wire_specs, check_vma=False)(p)
             update_local = engine.make_update_phase(opt, comm, "overlap")
-            update_phase = lambda p, g, s: steps_lib._shard_map(
-                update_local, mesh, (pspecs, pspecs, opt_specs),
-                (pspecs, opt_specs))(p, g, s)
+            update_phase = lambda p, g, s: jax.shard_map(
+                update_local, mesh=mesh, in_specs=(pspecs, pspecs, opt_specs),
+                out_specs=(pspecs, opt_specs), check_vma=False)(p, g, s)
             return engine.StepProgram(
                 optimizer=opt, comm=comm,
                 grad_phase=engine.make_grad_phase(LOSS),
