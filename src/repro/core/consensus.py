@@ -50,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.core import flatbuf
+from repro.core import flatbuf, phases
 from repro.core.faults import (FaultSchedule, arrival_masked_pi,
                                trivial_faults)
 from repro.core.faults import MAX_FAULT_PERIOD as _MAX_FAULT_PERIOD
@@ -939,6 +939,7 @@ class MixingStrategy:
             return wire
         return self._quantize_payloads(bufs, seed)
 
+    @phases.scoped("exchange")
     def exchange_stage(self, wire, step=None):
         """One round of neighbor exchange; fault-aware when engaged.
 
@@ -967,6 +968,7 @@ class MixingStrategy:
         return self._combine(nbrs, weights_q, scales, selfs)
 
     # -- carried wire state (schedule="overlap") ----------------------------
+    @phases.scoped("quantize")
     def advance_wire(self, bufs, old_wire, step):
         """Produce the wire state step ``step + 1`` will consume.
 
@@ -1012,6 +1014,7 @@ class MixingStrategy:
         send_age, ages = fo["init_state"]()
         return WireRing(slots=slots, send_age=send_age, ages=ages)
 
+    @phases.scoped("exchange")
     def continue_from_wire(self, bufs, wire, step):
         """Rounds 1..k of the per-step pipeline, round 1 from ``wire``.
 
@@ -1056,6 +1059,7 @@ class MixingStrategy:
         return self.continue_from_wire(bufs, wire, seed)
 
     # -- error feedback -----------------------------------------------------
+    @phases.scoped("quantize")
     def quantize_ef(self, bufs, seed, residual):
         """EF-compress the round-1 wire payload: ``Q(x + e)``.
 
@@ -1078,6 +1082,7 @@ class MixingStrategy:
             [c - d for c, d in zip(carried, deq)]))
         return wire, new_residual
 
+    @phases.scoped("quantize")
     def compress_ef(self, bufs, seed, residual, qwarm):
         """The compressor-axis generalization of :meth:`quantize_ef`.
 
@@ -1229,10 +1234,12 @@ def stacked_flat_comm(topology: Topology, *, interpret: Optional[bool] = None,
                 "the strategy records the dense bucket row counts")
         return rows[bi]
 
+    @phases.scoped("quantize")
     def quantize(bufs, seed, payload=0):
         return _quantize_wire_stacked(bufs, seed, n, exchange, interpret,
                                       payload=payload)
 
+    @phases.scoped("quantize")
     def compress(bufs, seed, qwarm):
         return _compress_wire_stacked(bufs, seed, n, program, interpret,
                                       qwarm)
@@ -1240,6 +1247,7 @@ def stacked_flat_comm(topology: Topology, *, interpret: Optional[bool] = None,
     def qwarm_init(bufs):
         return _qwarm_init_stacked(bufs, n, program)
 
+    @phases.scoped("exchange")
     def exchange_t(wire, t):
         # stacked simulation: every agent already sees the full stack — the
         # "exchange" is handing the wire payloads to the kernels with the
@@ -1291,6 +1299,7 @@ def stacked_flat_comm(topology: Topology, *, interpret: Optional[bool] = None,
             out.append(mixed.astype(sf.dtype))
         return out
 
+    @phases.scoped("exchange")
     def legacy_gather(bufs, seed):
         if exchange in ("f32", "bf16"):
             return ([_wire_payload(b, None, exchange, interpret)[0] for b in bufs],
@@ -1460,6 +1469,7 @@ def sharded_flat_comm(factors: Sequence[Tuple[str, Topology]], *,
     def _restore_lead(a):
         return a.reshape((1,) * lead + a.shape)
 
+    @phases.scoped("quantize")
     def compress(bufs, seed, qwarm):
         """Local squeezed buckets -> compressed wire entries (lead axes
         restored, like ``quantize``).  Top-k value-SR seeds derive from
@@ -1499,6 +1509,7 @@ def sharded_flat_comm(factors: Sequence[Tuple[str, Topology]], *,
         q0 = rank_init_q(param)
         return tuple(_restore_lead(q0) for _ in bufs)
 
+    @phases.scoped("quantize")
     def quantize(bufs, seed, payload=0):
         """Local squeezed buckets -> wire state (lead axes restored).
 
@@ -1601,6 +1612,7 @@ def sharded_flat_comm(factors: Sequence[Tuple[str, Topology]], *,
 
     branches = [_entry_branch(i) for i in range(len(entries))]
 
+    @phases.scoped("exchange")
     def exchange_t(wire, t):
         """Wire state -> (neighbor stacks, weights_q, scale stacks).
 
@@ -1651,6 +1663,7 @@ def sharded_flat_comm(factors: Sequence[Tuple[str, Topology]], *,
             out.append(mixed.astype(sf.dtype))
         return out
 
+    @phases.scoped("exchange")
     def legacy_gather(bufs, seed):
         if not (quantized and wire_combos0):
             stacked = []

@@ -2,8 +2,8 @@
 
 Every collaborative training step — stacked simulation
 (:class:`repro.core.trainer.CollaborativeTrainer`) and sharded production
-(:func:`repro.launch.steps.build_train_step`) — is the same five named
-phases; this module is their single definition and the front-ends only
+(:func:`repro.launch.steps.build_train_step`) — is the same sequence of
+named phases; this module is their single definition and the front-ends only
 supply mode-specific comm ops and (for the sharded mode) the ``shard_map``
 wrapper around the update group:
 
@@ -18,7 +18,14 @@ wrapper around the update group:
   stacked mode, one circulant ``lax.ppermute`` per shift per bucket in the
   sharded mode (``FlatComm.exchange_stage``);
 * ``update``   — the fused Pallas kernel per bucket (or the reference
-  per-leaf path for unfused optimizers).
+  per-leaf path for unfused optimizers);
+* ``unpack``   — the updated buffers back into the parameter pytree;
+* ``metrics``  — the step's loss and diagnostic outputs.
+
+Each phase traces under its profiler scope (:mod:`repro.core.phases`,
+``phase.<name>``): the whole update group sits under ``phase.update`` and
+the pack / quantize / exchange / unpack inside it under their own, so a
+device trace names the phase of every op.
 
 Schedules
 ---------
@@ -76,10 +83,10 @@ from repro.core.optim import (
     ExchangeResult,
     OptState,
 )
+from repro.core.phases import PHASES, scope  # noqa: F401  (PHASES re-exported)
 
 PyTree = Any
 
-PHASES = ("grad", "pack", "quantize", "exchange", "update")
 SCHEDULES = ("sync", "overlap")
 
 
@@ -449,14 +456,17 @@ class StepProgram:
         return state
 
     def step_fn(self, params: PyTree, opt_state: OptState, batch):
-        gp = self.optimizer.grad_params(params, opt_state)
-        (losses, metrics), grads = self.grad_phase(gp, batch)
-        new_params, new_state = self.update_phase(params, grads, opt_state)
-        out = {"loss": jnp.mean(losses)}
-        if self.extra_metrics is not None:
-            out.update(self.extra_metrics(new_params))
-        for k, v in metrics.items():
-            out[k] = jnp.mean(v)
+        with scope("grad"):
+            gp = self.optimizer.grad_params(params, opt_state)
+            (losses, metrics), grads = self.grad_phase(gp, batch)
+        with scope("update"):
+            new_params, new_state = self.update_phase(params, grads, opt_state)
+        with scope("metrics"):
+            out = {"loss": jnp.mean(losses)}
+            if self.extra_metrics is not None:
+                out.update(self.extra_metrics(new_params))
+            for k, v in metrics.items():
+                out[k] = jnp.mean(v)
         return new_params, new_state, out
 
 

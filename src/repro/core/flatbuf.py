@@ -66,6 +66,8 @@ from typing import Any, Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import phases
+
 PyTree = Any
 
 LANE = 128
@@ -205,6 +207,7 @@ def _row_groups(bucket: BucketSpec):
     return groups
 
 
+@phases.scoped("pack")
 def pack(tree: PyTree, spec: FlatSpec) -> List[jnp.ndarray]:
     """Pack ``tree`` into one ``(*lead, rows, 128)`` buffer per dtype bucket.
 
@@ -239,6 +242,7 @@ def pack(tree: PyTree, spec: FlatSpec) -> List[jnp.ndarray]:
     return out
 
 
+@phases.scoped("unpack")
 def unpack(bufs: Sequence[jnp.ndarray], spec: FlatSpec) -> PyTree:
     """Inverse of :func:`pack`: static slice + reshape per leaf."""
     if len(bufs) != spec.n_buckets:

@@ -236,10 +236,16 @@ class CollaborativeTrainer:
 
     # ------------------------------------------------------------------
     def step(self, batch: Dict[str, np.ndarray]) -> Dict[str, float]:
-        p, o, metrics = self._step_fn(self.state.params, self.state.opt_state, batch)
+        """One step; the host's two parts of it are profiler spans:
+        ``repro:dispatch`` (launching the jitted step) and ``repro:fetch``
+        (waiting for its metrics and logging them)."""
+        with jax.profiler.TraceAnnotation("repro:dispatch"):
+            p, o, metrics = self._step_fn(self.state.params,
+                                          self.state.opt_state, batch)
         self.state = TrainState(params=p, opt_state=o, step=self.state.step + 1)
-        out = {k: float(v) for k, v in metrics.items()}
-        self.history.log(self.state.step, **out)
+        with jax.profiler.TraceAnnotation("repro:fetch"):
+            out = {k: float(v) for k, v in metrics.items()}
+            self.history.log(self.state.step, **out)
         return out
 
     def evaluate(self, batch: Dict[str, np.ndarray]) -> Dict[str, float]:

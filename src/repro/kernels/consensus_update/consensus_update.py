@@ -743,6 +743,7 @@ def cdsgd_update_2d(
         out_specs=mat_spec,
         out_shape=jax.ShapeDtypeStruct((rows, lane), grad.dtype),
         input_output_aliases=_aliases(alias, ((grad_idx, 0),)),
+        name="cdsgd_update",
         interpret=resolve_interpret(interpret),
     )(*args)
 
@@ -795,6 +796,7 @@ def cdmsgd_update_2d(
             jax.ShapeDtypeStruct((rows, lane), momentum.dtype),
         ),
         input_output_aliases=_aliases(alias, ((g_idx, 0), (g_idx + 1, 1))),
+        name="cdmsgd_update",
         interpret=resolve_interpret(interpret),
     )(*args)
 
@@ -852,6 +854,7 @@ def cdmsgd_nesterov_update_2d(
             jax.ShapeDtypeStruct((rows, lane), grad.dtype),
         ),
         input_output_aliases=_aliases(alias, ((g_idx, 0), (g_idx + 1, 1))),
+        name="cdmsgd_nesterov_update",
         interpret=resolve_interpret(interpret),
     )(*args)
 
@@ -913,5 +916,6 @@ def cdadam_update_2d(
         ),
         input_output_aliases=_aliases(
             alias, ((g_idx, 0), (g_idx + 1, 1), (g_idx + 2, 2))),
+        name="cdadam_update",
         interpret=resolve_interpret(interpret),
     )(*args)

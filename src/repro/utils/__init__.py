@@ -14,7 +14,7 @@ from repro.utils.tree import (
     tree_bytes,
 )
 from repro.utils.prng import split_key, fold_in_name
-from repro.utils.metrics import CSVLogger, MetricHistory
+from repro.utils.metrics import MetricHistory
 
 __all__ = [
     "tree_add",
@@ -30,6 +30,5 @@ __all__ = [
     "tree_bytes",
     "split_key",
     "fold_in_name",
-    "CSVLogger",
     "MetricHistory",
 ]
