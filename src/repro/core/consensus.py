@@ -2177,12 +2177,19 @@ def consensus_error_stacked(x: jnp.ndarray) -> jnp.ndarray:
 
 
 def consensus_error_pytree(tree: PyTree) -> jnp.ndarray:
-    """Aggregate consensus error over an agent-stacked pytree."""
+    """Aggregate consensus error over an agent-stacked pytree:
+    ``mean_j sqrt(sum_leaves ||x_j - mean(x)||^2)``, in float32.
+
+    Each leaf's agent mean is a sum of its ``A`` agent slices, not a
+    reduction over the agent axis, and nothing is reshaped: the compiler
+    then reads every parameter once, in one fused reduction (a reduction
+    over axis 0 followed by a flattened sum of squares reads the tree
+    several times over on a TPU)."""
     leaves = jax.tree.leaves(tree)
     n = leaves[0].shape[0]
-    mean_sq = jnp.zeros((n,), dtype=jnp.float32)
+    sq = [jnp.float32(0.0)] * n
     for x in leaves:
-        mean = jnp.mean(x, axis=0, keepdims=True)
-        d = (x - mean).reshape(n, -1).astype(jnp.float32)
-        mean_sq = mean_sq + jnp.sum(d * d, axis=1)
-    return jnp.mean(jnp.sqrt(mean_sq))
+        xs = [x[j].astype(jnp.float32) for j in range(n)]
+        mean = sum(xs) / n
+        sq = [s + jnp.sum((xj - mean) ** 2) for s, xj in zip(sq, xs)]
+    return jnp.mean(jnp.sqrt(jnp.stack(sq)))

@@ -82,3 +82,29 @@ def test_consensus_error_pytree_zero_at_consensus():
     x = jnp.ones((4, 3))
     tree = {"w": x, "b": 2 * x}
     assert float(consensus_error_pytree(tree)) == pytest.approx(0.0, abs=1e-6)
+
+
+# per-agent leaf shapes of rank 0 to 3: a scalar, vectors, a matrix and a
+# stacked-layer leaf (rank 4 once the agent axis leads)
+_LEAF_SHAPES = {"s": (), "v": (7,), "b": (33,), "w": (3, 5), "l": (2, 3, 4)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("agents", [2, 4, 16])
+def test_consensus_error_pytree_matches_float64_reference(agents, dtype):
+    """``mean_j sqrt(sum_leaves ||x_j - mean||^2)`` against NumPy float64 on
+    the same (rounded) values: the metric is computed in float32 whatever
+    the leaves' dtype."""
+    rng = np.random.default_rng(agents)
+    tree = {k: jnp.asarray(rng.normal(size=(agents,) + s), dtype)
+            for k, s in _LEAF_SHAPES.items()}
+    sq = np.zeros(agents)
+    for x in jax.tree.leaves(tree):
+        x64 = np.asarray(x.astype(jnp.float32), np.float64)
+        d = (x64 - x64.mean(axis=0)).reshape(agents, -1)
+        sq += np.sum(d * d, axis=1)
+    want = np.mean(np.sqrt(sq))
+    got = consensus_error_pytree(tree)
+    assert got.dtype == jnp.float32
+    assert float(got) == pytest.approx(want, rel=1e-5)

@@ -6,8 +6,9 @@ a block shape Mosaic rejects, a primitive with no Mosaic lowering — fails
 in this file at no chip time.  Each test compiles one kernel with
 ``interpret=False`` on the bf16 flat bucket of rwkv6-1.6b at its published
 widths (two layers) and asserts the compiled module holds a Mosaic kernel
-(``tpu_custom_call``).  The topology is described inside a module fixture:
-only the worker that runs these tests loads the TPU library.
+(``tpu_custom_call``); one more pins how many bytes the step's
+consensus-error metric reads.  The topology is described inside a module
+fixture: only the worker that runs these tests loads the TPU library.
 """
 
 import dataclasses
@@ -19,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core import flatbuf
+from repro.core.consensus import consensus_error_pytree
 from repro.kernels.consensus_update import consensus_update as cu
 from repro.kernels.consensus_update.ops import cdmsgd_update_flat
 from repro.kernels.consensus_update.topk import topk_threshold_2d
@@ -44,11 +46,16 @@ def one_chip(topo):
 
 
 @pytest.fixture(scope="module")
-def rows():
-    """Rows of the one (bf16) flat bucket of a 2-layer rwkv6-1.6b."""
+def shapes():
+    """Parameter shapes of a 2-layer rwkv6-1.6b."""
     cfg = dataclasses.replace(get_config("rwkv6-1.6b"), n_layers=2)
-    shapes = jax.eval_shape(
+    return jax.eval_shape(
         lambda: init_params(model_template(cfg), jax.random.PRNGKey(0)))
+
+
+@pytest.fixture(scope="module")
+def rows(shapes):
+    """Rows of the one (bf16) flat bucket of a 2-layer rwkv6-1.6b."""
     (bucket,) = flatbuf.make_flat_spec(shapes).buckets
     assert bucket.dtype == jnp.bfloat16
     return bucket.rows
@@ -113,6 +120,18 @@ def test_topk_threshold_compiles(one_chip, rows):
     text = _compile_text(
         lambda a: topk_threshold_2d(a, rows, interpret=False), x)
     assert "tpu_custom_call" in text
+
+
+def test_consensus_error_reads_the_parameters_once(one_chip, shapes):
+    """The step's consensus-error metric on the agent-stacked tree: one
+    fused read of each leaf, not a reduction over the agent axis followed
+    by a flattened sum of squares (which read the tree 5.6 times)."""
+    stacked = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        (A,) + s.shape, s.dtype, sharding=one_chip), shapes)
+    tree_bytes = sum(s.size * s.dtype.itemsize
+                     for s in jax.tree.leaves(stacked))
+    compiled = jax.jit(consensus_error_pytree).lower(stacked).compile()
+    assert compiled.cost_analysis()["bytes accessed"] < 2 * tree_bytes
 
 
 def test_sparse_update_refuses_compiled_mode():
