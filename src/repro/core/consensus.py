@@ -2180,11 +2180,14 @@ def consensus_error_pytree(tree: PyTree) -> jnp.ndarray:
     """Aggregate consensus error over an agent-stacked pytree:
     ``mean_j sqrt(sum_leaves ||x_j - mean(x)||^2)``, in float32.
 
-    Each leaf's agent mean is a sum of its ``A`` agent slices, not a
-    reduction over the agent axis, and nothing is reshaped: the compiler
-    then reads every parameter once, in one fused reduction (a reduction
-    over axis 0 followed by a flattened sum of squares reads the tree
-    several times over on a TPU)."""
+    Each leaf's agent mean is a sum of its agent slices, not a reduction
+    over the agent axis, and nothing is reshaped: on a TPU the compiler
+    then reads every parameter once, in one fused reduction per leaf.  The
+    barrier makes it read the parameters as they are stored: inside a step
+    the compiler would otherwise fuse each agent slice into the ops that
+    produce the leaf (``flatbuf.unpack``) and redo their work once per
+    slice."""
+    tree = jax.lax.optimization_barrier(tree)
     leaves = jax.tree.leaves(tree)
     n = leaves[0].shape[0]
     sq = [jnp.float32(0.0)] * n
